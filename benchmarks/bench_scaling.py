@@ -129,7 +129,8 @@ def run_timeline(n_a: int, n_b: int, n_d: int, *, prompt_len: int = 24,
     # decoders end clean: all pages + tail slots back
     for d in decoders:
         assert len(d.pool._free) == d.pool.n_pages
-        assert len(d._tail_free) == 16 and not d._pending
+        n_tails = d.tail_buf.size // (cfg.vocab * 4)
+        assert len(d._tail_free) == n_tails and not d._pending
     # every route went through an epoch view, and epochs only moved forward
     assert len(sched.routing_log) >= n_total
     assert sched.view_epochs == sorted(sched.view_epochs)

@@ -55,7 +55,7 @@ from repro.models.moe import init_moe, moe_dense
 mcfg = get_config("qwen3-moe-30b-a3b").reduced()
 n_dev = jax.device_count()
 if n_dev >= 4:
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     p = init_moe(jax.random.PRNGKey(0), mcfg, jnp.float32)
     h = jax.random.normal(jax.random.PRNGKey(1), (64, mcfg.d_model)) * 0.5

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..models.common import rms_norm
 from .context import current_mesh, data_axes
 
@@ -64,13 +63,13 @@ def moe_a2a(p, h: jax.Array, cfg, ep_axis: str = "model",
             ) -> Tuple[jax.Array, jax.Array]:
     """Paper-style expert-parallel MoE layer.  h: (T, D) normalised tokens.
 
-    Must run inside a mesh context with ``ep_axis`` present.  Falls back to
-    the scatter path when no mesh is active (single-device tests).
+    Needs a mesh with ``ep_axis``: passed in, or active via ``use_mesh``.
     """
     mesh = mesh or current_mesh()
     if mesh is None or ep_axis not in mesh.axis_names:
-        from ..models.moe import moe_scatter
-        return moe_scatter(p, h, cfg)
+        raise ValueError(
+            f"moe_a2a needs a mesh with an {ep_axis!r} axis (pass mesh= or "
+            f"enter comm.use_mesh); got {mesh}")
 
     import math
 
@@ -176,7 +175,7 @@ def moe_a2a(p, h: jax.Array, cfg, ep_axis: str = "model",
         args += [p["swg"], p["swu"], p["swd"]]
     out_specs = (P((*daxes, ep_axis), None), P())
 
-    y, aux = shard_map(local, mesh=mesh, in_specs=in_specs,
+    y, aux = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(*args)
     return y, aux
 
@@ -238,7 +237,7 @@ def moe_ep_psum(p, h: jax.Array, cfg, ep_axis: str,
         in_specs = in_specs + (P(None, None),) * 3
         args += [p["swg"], p["swu"], p["swd"]]
     out_specs = (P(daxes if daxes else None, None), P())
-    y, aux = shard_map(local, mesh=mesh, in_specs=in_specs,
+    y, aux = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(*args)
     return y, aux
 

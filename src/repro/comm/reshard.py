@@ -20,7 +20,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..roofline.hlo_cost import analyze_hlo
 
 
@@ -70,7 +69,7 @@ def fsdp_to_tp(x, mesh: Mesh, *, daxes=("data",), ep_axis: str = "model"):
             mine = jax.lax.all_gather(mine, tuple(daxes), axis=0, tiled=True)
         return mine
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P((*daxes, ep_axis), None),
         out_specs=P(None, ep_axis), check_vma=False)(x)

@@ -16,12 +16,7 @@ import numpy as np
 
 def _accel_backend() -> bool:
     jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return jax is not None and jax.default_backend() != "cpu"
 
 
 def moe_pack_host(rows: np.ndarray, perm: np.ndarray) -> np.ndarray:
@@ -34,9 +29,7 @@ def moe_pack_host(rows: np.ndarray, perm: np.ndarray) -> np.ndarray:
     perm = np.asarray(perm)
     if _accel_backend():
         from . import ops
-        import jax.numpy as jnp
-        return np.asarray(ops.moe_pack(jnp.asarray(rows),
-                                       jnp.asarray(perm.astype(np.int32))))
+        return np.asarray(ops.moe_pack(rows, perm.astype(np.int32)))
     rows = np.asarray(rows)
     out = rows[np.maximum(perm, 0)]
     neg = perm < 0
@@ -47,7 +40,8 @@ def moe_pack_host(rows: np.ndarray, perm: np.ndarray) -> np.ndarray:
 
 def moe_combine_host(ye: np.ndarray, inv: np.ndarray,
                      gates: np.ndarray) -> np.ndarray:
-    """Weighted combine (fp32 accumulation) for the moekit source half.
+    """Weighted combine (fp32 accumulation and result) for the moekit
+    source half.
 
     ``ye``: (M, D) packed expert-output rows; ``inv``: (T, K) packed-row
     index per (token, slot), -1 => dropped; ``gates``: (T, K) weights.
@@ -55,14 +49,13 @@ def moe_combine_host(ye: np.ndarray, inv: np.ndarray,
     slots by expert id get bit-identical fp32 sums to a dense
     ascending-expert oracle.
     """
+    inv = np.asarray(inv)
     if _accel_backend():
         from . import ops
-        import jax.numpy as jnp
         return np.asarray(ops.moe_combine(
-            jnp.asarray(ye), jnp.asarray(np.asarray(inv, np.int32)),
-            jnp.asarray(gates)))
+            ye, inv.astype(np.int32), np.asarray(gates, np.float32),
+            out_dtype=np.float32))
     ye = np.asarray(ye)
-    inv = np.asarray(inv)
     gates = np.asarray(gates, np.float32)
     T, K = inv.shape
     y = np.zeros((T, ye.shape[1]), np.float32)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) kernels execute in ``interpret=True`` mode — the
-kernel body runs in Python for correctness validation; on TPU the same
-``pl.pallas_call`` lowers to Mosaic.  ``INTERPRET`` can be forced for tests.
+Called directly, the kernels execute in ``interpret=True`` mode when the
+default backend is the CPU — the kernel body runs in Python for correctness
+validation; on TPU the same ``pl.pallas_call`` lowers to Mosaic.
+``INTERPRET`` can be forced for tests.
 """
 
 from __future__ import annotations
@@ -13,9 +14,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import moe_combine as _combine
-from . import moe_pack as _pack
+from . import moe_combine as _combine_k
+from . import moe_pack as _pack_k
 from . import paged_copy as _paged
+from . import ref
 from . import ssd_scan as _ssd
 
 INTERPRET: Optional[bool] = None  # None => auto (CPU -> True)
@@ -27,21 +29,16 @@ def _interp() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@jax.custom_vjp
-def moe_pack(x: jax.Array, perm: jax.Array) -> jax.Array:
-    """Differentiable row gather (Pallas); -1 rows emit zeros.
-
-    Linear in x: the VJP scatter-adds cotangent rows back (pure jnp — the
-    backward is bandwidth-trivial compared to the expert GEMMs).
-    """
-    return _pack.moe_pack(x, perm, interpret=_interp())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pack(x: jax.Array, perm: jax.Array, interpret: bool) -> jax.Array:
+    return _pack_k.moe_pack(x, perm, interpret=interpret)
 
 
-def _pack_fwd(x, perm):
-    return moe_pack(x, perm), (perm, x.shape[0])
+def _pack_fwd(x, perm, interpret):
+    return _pack(x, perm, interpret), (perm, x.shape[0])
 
 
-def _pack_bwd(res, dy):
+def _pack_bwd(interpret, res, dy):
     perm, T = res
     keep = perm >= 0
     dx = jnp.zeros((T, dy.shape[1]), dy.dtype).at[
@@ -50,20 +47,31 @@ def _pack_bwd(res, dy):
     return dx, None
 
 
-moe_pack.defvjp(_pack_fwd, _pack_bwd)
+_pack.defvjp(_pack_fwd, _pack_bwd)
 
 
-@jax.custom_vjp
-def moe_combine(ye: jax.Array, inv: jax.Array, gates: jax.Array) -> jax.Array:
-    """Differentiable weighted combine (Pallas), fp32 accumulation."""
-    return _combine.moe_combine(ye, inv, gates, interpret=_interp())
+@jax.jit
+def moe_pack(x: jax.Array, perm: jax.Array) -> jax.Array:
+    """Differentiable row gather (Pallas); -1 rows emit zeros.
+
+    Linear in x: the VJP scatter-adds cotangent rows back (pure jnp — the
+    backward is bandwidth-trivial compared to the expert GEMMs).
+    """
+    return _pack(x, perm, _interp())
 
 
-def _combine_fwd(ye, inv, gates):
-    return moe_combine(ye, inv, gates), (ye, inv, gates)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(ye: jax.Array, inv: jax.Array, gates: jax.Array,
+             interpret: bool, out_dtype) -> jax.Array:
+    return _combine_k.moe_combine(ye, inv, gates, out_dtype=out_dtype,
+                                  interpret=interpret)
 
 
-def _combine_bwd(res, dy):
+def _combine_fwd(ye, inv, gates, interpret, out_dtype):
+    return _combine(ye, inv, gates, interpret, out_dtype), (ye, inv, gates)
+
+
+def _combine_bwd(interpret, out_dtype, res, dy):
     ye, inv, gates = res
     T, K = inv.shape
     M = ye.shape[0]
@@ -81,31 +89,33 @@ def _combine_bwd(res, dy):
     return d_ye, None, d_g
 
 
-moe_combine.defvjp(_combine_fwd, _combine_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-# Host-proxy entry points (moekit's receiver shuffle and combine reduce):
-# numpy-first wrappers living in the jax-free `kernels.host` module; they
-# delegate to the Pallas kernels above when an accelerator backend is live.
-from .host import moe_combine_host, moe_pack_host  # noqa: E402,F401
+@functools.partial(jax.jit, static_argnames=("out_dtype",))
+def moe_combine(ye: jax.Array, inv: jax.Array, gates: jax.Array, *,
+                out_dtype=None) -> jax.Array:
+    """Differentiable weighted combine (Pallas), fp32 accumulation; the
+    result is ``out_dtype`` (default ``ye.dtype``)."""
+    return _combine(ye, inv, gates, _interp(), out_dtype)
 
 
+# In-graph callers (``comm.moe_a2a``) select by the platform the program is
+# compiled for: the Pallas kernels on TPU, the pure-jnp oracle (an XLA
+# gather) elsewhere.  Interpret-mode Pallas inside a compiled hot path lowers
+# to millions of row-sized loop ops — fine for validating the kernel,
+# catastrophic inside a 48-layer program.
 def moe_pack_auto(x: jax.Array, perm: jax.Array) -> jax.Array:
-    """Backend-adaptive pack: the Pallas kernel on TPU, the pure-jnp oracle
-    (an XLA gather) elsewhere.  Interpret-mode Pallas inside a compiled hot
-    path lowers to millions of row-sized loop ops — fine for validating the
-    kernel, catastrophic inside the 48-layer dry-run (§Perf iteration E)."""
-    if jax.default_backend() == "cpu":
-        from . import ref
-        return ref.moe_pack(x, perm)
-    return moe_pack(x, perm)
+    return jax.lax.platform_dependent(
+        x, perm, tpu=lambda x, perm: _pack(x, perm, False),
+        default=ref.moe_pack)
 
 
 def moe_combine_auto(ye: jax.Array, inv: jax.Array, gates: jax.Array) -> jax.Array:
-    if jax.default_backend() == "cpu":
-        from . import ref
-        return ref.moe_combine(ye, inv, gates)
-    return moe_combine(ye, inv, gates)
+    return jax.lax.platform_dependent(
+        ye, inv, gates,
+        tpu=lambda ye, inv, gates: _combine(ye, inv, gates, False, None),
+        default=ref.moe_combine)
 
 
 @functools.partial(jax.jit, static_argnames=("block_e",))

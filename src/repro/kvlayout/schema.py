@@ -15,7 +15,9 @@ A :class:`KvSchema` names each cache array as a *component* with:
 * ``layers``   — the model layer ids whose compute produces each stack
   entry (this is what maps UvmWatcher layer progress to transferable
   state);
-* ``dtype``    — numpy dtype string of the wire bytes;
+* ``dtype``    — numpy dtype name of the wire bytes (``"bfloat16"``,
+  ``"float32"``; a name, because bfloat16's ``dtype.str`` is the
+  uninterpretable ``"<V2"``);
 * ``kind``     — the component's extent semantics:
     - ``token``:  one row per *prompt token* (paged over ``page_tokens``);
     - ``ring``:   a ring buffer of ``min(max_len, window)`` token slots,
@@ -42,6 +44,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
+import ml_dtypes  # noqa: F401  (registers the "bfloat16" dtype name)
 import numpy as np
 
 # Decode headroom baked into the handoff cache: both ends of a transfer
@@ -64,7 +67,7 @@ class KvComponent:
     name: str
     kind: str
     layers: Tuple[int, ...]        # producing model layer per stack entry
-    dtype: str                     # numpy dtype str (e.g. "<f4")
+    dtype: str                     # numpy dtype name (e.g. "float32")
     token_bytes: int = 0           # bytes/token/stack-layer (token|ring|fixed)
     window: int = 0                # ring capacity cap (ring; 0 = max_len)
     fixed_tokens: int = 0          # token rows (fixed)
@@ -202,8 +205,8 @@ def schema_from_config(cfg, page_tokens: int = 16) -> KvSchema:
     completes each stack entry, which is what lets the Prefiller's
     UvmWatcher trigger per-span transfers for ANY cache shape.
     """
-    dt = np.dtype(cfg.param_dtype).str
-    f4 = np.dtype(np.float32).str
+    dt = np.dtype(cfg.param_dtype).name
+    f4 = np.dtype(np.float32).name
     itemsize = np.dtype(cfg.param_dtype).itemsize
     comps: List[KvComponent] = []
 

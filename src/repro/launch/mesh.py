@@ -10,8 +10,15 @@ real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from ..compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis typed ``Auto`` (GSPMD propagation
+    inside ``jit``; ``shard_map`` bodies name their collectives)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -26,6 +26,7 @@ from ..data import Batcher, SyntheticCorpus
 from ..models import init_params
 from ..optim import init_adamw
 from . import steps as St
+from .cache import use_compile_cache
 from .mesh import make_local_mesh, make_production_mesh
 
 
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -13,7 +13,6 @@ reduction spans shards (GSPMD inserts the collectives; see EXPERIMENTS.md
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -25,13 +24,10 @@ NEG_INF = -1e30
 
 # Prefill attention implementation: the Pallas flash kernel keeps the score
 # tiles and running statistics in VMEM (the dominant residual memory-term
-# contributor per EXPERIMENTS §Perf).  Enabled automatically on TPU; the
-# chunked-jnp path remains the CPU/host default.  FORCE_FLASH is a test hook.
+# contributor per EXPERIMENTS §Perf).  It is selected for programs compiled
+# for a TPU; the chunked-jnp path serves every other platform.  FORCE_FLASH
+# is a test hook that runs the kernel everywhere (interpret mode off-TPU).
 FORCE_FLASH: bool = False
-
-
-def _use_flash() -> bool:
-    return FORCE_FLASH or jax.default_backend() == "tpu"
 
 
 def init_attn(key, cfg, dtype) -> Dict[str, jax.Array]:
@@ -175,28 +171,55 @@ def attn_prefill(p: Dict[str, jax.Array], x: jax.Array, cfg, *,
     else:
         kpos = jnp.arange(src.shape[1], dtype=jnp.int32)
         causal = False
-    # Pallas flash path (TPU): self-attention over contiguous positions.
-    # Window comes either from the static band or a trace-time constant.
+    # Pallas flash path: self-attention with a window known at trace time
+    # (a scanned pattern arch passes a traced per-layer window: chunked).
     win_static = static_window
     if win_static is None:
         if window is None:
             win_static = 0             # full causal attention
-        else:
-            try:
-                w = int(window)        # concrete per-arch constant
-                win_static = w if w > 0 else 0
-            except Exception:
-                win_static = None      # traced (mixed-layer scan) -> chunked
-    if (_use_flash() and kv_src is None and win_static is not None
-            and S % 16 == 0):
-        from ..kernels import ops as kops
-        G = H // K
-        kb = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1)   # (B,H,S,Dh)
-        vb = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1)
-        qb = q.transpose(0, 2, 1, 3)
-        o = kops.flash_attention(qb, kb, vb, causal=True,
-                                 window=max(win_static, 0))
-        o = o.transpose(0, 2, 1, 3)
+        elif not isinstance(window, jax.core.Tracer):
+            win_static = max(int(window), 0)
+    if kv_src is None and win_static is not None and S % 16 == 0:
+        def flash(q, k, v, interpret):
+            from ..kernels import flash_attention as fa
+            G = H // K
+            kb = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1)   # (B,H,S,Dh)
+            vb = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1)
+            o = fa.flash_attention(q.transpose(0, 2, 1, 3), kb, vb,
+                                   causal=True, window=win_static,
+                                   interpret=interpret)
+            return o.transpose(0, 2, 1, 3)
+
+        def chunked(q, k, v, qpos, kpos):
+            return chunked_attention(q, k, v, qpos, kpos, causal=True,
+                                     window=window,
+                                     static_window=static_window)
+
+        # positions are arguments, not closed over: a custom_vjp may not
+        # capture the tracers of an enclosing scan or checkpoint
+        @jax.custom_vjp
+        def attend(q, k, v, qpos, kpos):
+            if FORCE_FLASH:
+                from ..kernels.ops import _interp
+                return flash(q, k, v, _interp())
+            # the kernel where the program is compiled for a TPU
+            return jax.lax.platform_dependent(
+                q, k, v, qpos, kpos,
+                tpu=lambda q, k, v, *_: flash(q, k, v, False),
+                default=chunked)
+
+        def attend_fwd(q, k, v, qpos, kpos):
+            return attend(q, k, v, qpos, kpos), (q, k, v, qpos, kpos)
+
+        def attend_bwd(res, do):
+            # the flash kernel is forward-only: differentiate chunked
+            q, k, v, qpos, kpos = res
+            dq, dk, dv = jax.vjp(lambda q, k, v: chunked(q, k, v, qpos, kpos),
+                                 q, k, v)[1](do)
+            return dq, dk, dv, None, None
+
+        attend.defvjp(attend_fwd, attend_bwd)
+        o = attend(q, k, v, positions, kpos)
     else:
         o = chunked_attention(q, k, v, positions, kpos, causal=causal,
                               window=window, static_window=static_window)

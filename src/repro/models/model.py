@@ -591,8 +591,12 @@ def prefill(params: Params, tokens: jax.Array, cfg, *,
         p_l, window_l, is_cross_l = xs
 
         def self_branch(x):
-            out, (k, v) = attn_prefill(p_l["attn"], x, cfg, window=window_l,
-                                       positions=positions, return_kv=True)
+            # without a global/local pattern every self-attention layer of
+            # this stack attends fully: a static window (None) lets
+            # attn_prefill take the flash kernel
+            out, (k, v) = attn_prefill(
+                p_l["attn"], x, cfg, positions=positions, return_kv=True,
+                window=window_l if cfg.global_every else None)
             ck = jnp.zeros((B, Sv, K, Dh), x.dtype) if cfg.family == "vlm" else None
             return out, pad_kv(k), pad_kv(v), ck, ck
 
@@ -745,3 +749,11 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     x, (k, v) = jax.lax.scan(body, x, xs)
     new_cache["k"], new_cache["v"] = k, v
     return _unembed(params, x, cfg)[:, 0], new_cache
+
+
+# Serving entry points: one compiled program per (config, shapes), with the
+# frozen ModelConfig static.  Called eagerly, every lax.scan above traces a
+# fresh closure and recompiles on every call.
+prefill_jit = jax.jit(prefill, static_argnames=("cfg", "max_len", "moe_mode",
+                                                "use_kernel"))
+decode_step_jit = jax.jit(decode_step, static_argnames=("cfg", "moe_mode"))

@@ -13,7 +13,7 @@ Low-latency decode fast path.  Protocol per rank and MoE layer invocation:
     4. receiver completion = ImmCounter over token writes; the grouped-GEMM
        layout is recovered from the exchanged routes ALONE (no peeking at
        peer state) as a route-derived permutation executed by a single
-       fancy-index gather (``repro.kernels.ops.moe_pack_host``)
+       fancy-index gather (``repro.kernels.host.moe_pack_host``)
     => at most TWO data WRITEs per inter-node peer per round (private +
        shared), plus the route write — the paper's §6 bound, honestly.
 
@@ -22,7 +22,7 @@ Low-latency decode fast path.  Protocol per rank and MoE layer invocation:
     a route-derived permutation packs them (source-major) and the per-source
     row slices ride as ``PayloadDst`` gather-into-snapshot payloads (no
     staging copy).  Each source un-permutes and reduces with its gates in
-    fp32 via ``repro.kernels.ops.moe_combine_host``.
+    fp32 via ``repro.kernels.host.moe_combine_host``.
 
 Offsets are derived on BOTH sides purely from ``routes_buf``: endpoints
 exchange only :class:`PeerPorts` (rank + MrDescs), so no endpoint can read
@@ -340,7 +340,7 @@ class MoEEndpoint:
         """Shuffle received bytes into per-local-expert dense slabs (the
         paper's receiver half feeding the Grouped GEMM): a route-derived
         permutation over the receive rows, executed as ONE fancy-index
-        gather (``kernels.ops.moe_pack_host`` — Pallas on TPU, numpy ref
+        gather (``kernels.host.moe_pack_host`` — Pallas on TPU, numpy ref
         fallback on CPU)."""
         from ..kernels.host import moe_pack_host
         cfg = self.cfg
@@ -433,7 +433,7 @@ class MoEEndpoint:
     def combine_result(self, ctx: Dict, gates: np.ndarray,
                        dtype=np.float32) -> np.ndarray:
         """Un-permute the combine buffer and reduce with gates (fp32):
-        a route-derived segment reduction via ``kernels.ops
+        a route-derived segment reduction via ``kernels.host
         .moe_combine_host`` — O(top_k) vector ops, no per-token Python."""
         from ..kernels.host import moe_combine_host
         cfg = self.cfg
@@ -450,6 +450,6 @@ class MoEEndpoint:
         inv_sorted = np.take_along_axis(inv, sort_k, axis=1)
         eids_sorted = np.take_along_axis(ctx["eids"], sort_k, axis=1)
         gk = gates[np.arange(T)[:, None], eids_sorted].astype(np.float32)
-        elems = tb // dtype().itemsize
+        elems = tb // np.dtype(dtype).itemsize
         rows = self.comb_buf.view(dtype).reshape(-1, elems)[:T * R]
         return moe_combine_host(rows, inv_sorted, gk)

@@ -59,7 +59,7 @@ def run_moe_layer(fabric: Fabric, eps: List[MoEEndpoint],
         ep = eps[r]
         slabs = ep.gather_expert_tokens(ctxs[r])
         outs = []
-        elems = cfg.token_bytes // dtype().itemsize
+        elems = cfg.token_bytes // np.dtype(dtype).itemsize
         for e_loc, slab in enumerate(slabs):
             e = r * cfg.e_local + e_loc
             x = slab.view(dtype).reshape(slab.shape[0], elems)

@@ -50,7 +50,7 @@ from ..ctrl import ControlClient, ControlPlane, CtrlRetryPolicy
 from ..ctrl import messages as m
 from ..kvlayout import (DECODE_MARGIN, KvSchema, TransferPlan, fill_cache,
                         schema_from_config, stage_cache)
-from ..models import decode_step, init_cache, prefill
+from ..models import decode_step_jit, init_cache, prefill_jit
 from ..obs import traced_phase
 from .kvpool import KvPool
 
@@ -87,6 +87,12 @@ def _cached_plan(plans: Dict[int, TransferPlan], schema: KvSchema,
     return plan
 
 
+def _pool_pages(schema: KvSchema, max_seq_len: int, max_inflight: int) -> int:
+    """KV pool pages that hold ``max_inflight`` concurrent handoffs of
+    prompts up to ``max_seq_len`` tokens (the plan's slots per handoff)."""
+    return TransferPlan(schema, max_seq_len).n_slots * max_inflight
+
+
 def disagg_unsupported_reason(cfg) -> Optional[str]:
     """Why the §4 KvCache protocol cannot serve ``cfg`` (None = it can).
 
@@ -121,10 +127,14 @@ def _vision_batch(cfg, vision_emb) -> Optional[jnp.ndarray]:
 
 
 class Prefiller:
-    """Prefill node: owns model params and a KV pool as WRITE source."""
+    """Prefill node: owns model params and a KV pool as WRITE source.
+
+    The pool holds ``max_inflight`` handoffs of prompts up to
+    ``max_seq_len`` tokens, in pages of the model's ``KvSchema``."""
 
     def __init__(self, fabric: Fabric, node: str, cfg, params, *,
-                 nic: str = "efa", page_tokens: int = 16, n_pages: int = 512,
+                 nic: str = "efa", page_tokens: int = 16,
+                 max_seq_len: int = 64, max_inflight: int = 32,
                  layer_compute_us: float = 50.0,
                  ctrl: Optional[ControlPlane] = None,
                  peer_id: Optional[str] = None, renew_us: float = 500.0,
@@ -139,6 +149,7 @@ class Prefiller:
         self.fabric = fabric
         self.nic = nic
         self.schema = schema_from_config(cfg, page_tokens)
+        n_pages = _pool_pages(self.schema, max_seq_len, max_inflight)
         self.pool = KvPool(self.engine, self.schema, n_pages)
         self._plans: Dict[int, TransferPlan] = {}   # seq_len -> compiled plan
         self.layer_compute_us = layer_compute_us
@@ -257,9 +268,9 @@ class Prefiller:
         # derive cache geometry from plan.max_len so ring slot assignment
         # and padding agree bit-for-bit.
         tokens = jnp.asarray(req.input_ids, jnp.int32)[None]
-        logits, cache = prefill(self.params, tokens, cfg,
-                                max_len=plan.max_len, moe_mode="dense",
-                                vision_emb=_vision_batch(cfg, req.vision_emb))
+        logits, cache = prefill_jit(
+            self.params, tokens, cfg, max_len=plan.max_len, moe_mode="dense",
+            vision_emb=_vision_batch(cfg, req.vision_emb))
         logits = logits[..., :cfg.vocab]   # drop vocab padding
 
         # stage EVERY schema component into pool slots, canonical order
@@ -372,12 +383,15 @@ class Decoder:
     With ``ctrl=`` the decoder also serves the elastic wire path: the
     scheduler SENDs ``SubmitReq``s here, completion is reported back via
     ``ReqDone``, and ``CancelReq`` (failover) frees the attempt's pages and
-    tail slot so nothing leaks when a prefiller dies mid-transfer.
+    tail slot so nothing leaks when a prefiller dies mid-transfer.  Pool
+    and tail slots are sized as the Prefiller's: ``max_inflight`` handoffs
+    of prompts up to ``max_seq_len`` tokens.
     """
 
     def __init__(self, fabric: Fabric, node: str, cfg, params, *,
-                 nic: str = "efa", page_tokens: int = 16, n_pages: int = 512,
-                 max_tail: int = 16, ctrl: Optional[ControlPlane] = None,
+                 nic: str = "efa", page_tokens: int = 16,
+                 max_seq_len: int = 64, max_inflight: int = 32,
+                 ctrl: Optional[ControlPlane] = None,
                  peer_id: Optional[str] = None, renew_us: float = 500.0,
                  max_renewals: int = 256, host: Optional[str] = None,
                  ctrl_retry: Optional[CtrlRetryPolicy] = None):
@@ -388,12 +402,14 @@ class Decoder:
         # host: physical machine identity (NVLink domain) — see Prefiller
         self.engine = fabric.add_engine(node, nic=nic, host=host)
         self.schema = schema_from_config(cfg, page_tokens)
+        n_pages = _pool_pages(self.schema, max_seq_len, max_inflight)
         self.pool = KvPool(self.engine, self.schema, n_pages)
         self._plans: Dict[int, TransferPlan] = {}
         tail_bytes = cfg.vocab * 4
-        self.tail_buf = np.zeros(max_tail * tail_bytes, np.uint8)
+        # one tail (last-token logits) slot per in-flight handoff
+        self.tail_buf = np.zeros(max_inflight * tail_bytes, np.uint8)
         self.tail_handle, self.tail_desc = self.engine.reg_mr(self.tail_buf)
-        self._tail_free = list(range(max_tail))
+        self._tail_free = list(range(max_inflight))
         self._imm_next = 1
         self.alive = True
         self.draining = False
@@ -631,9 +647,9 @@ class Decoder:
         toks = [int(np.argmax(logits[0]))]
         pos = r["seq_len"]
         for _ in range(n_decode - 1):
-            lg, cache = decode_step(self.params, jnp.asarray([[toks[-1]]]),
-                                    jnp.asarray([pos], jnp.int32), cache, cfg,
-                                    moe_mode="dense")
+            lg, cache = decode_step_jit(
+                self.params, jnp.asarray([[toks[-1]]]),
+                jnp.asarray([pos], jnp.int32), cache, cfg, moe_mode="dense")
             toks.append(int(jnp.argmax(lg[0])))
             pos += 1
         r["tokens"] = toks

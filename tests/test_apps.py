@@ -73,6 +73,42 @@ def test_disagg_multiple_requests_and_page_reuse():
     assert len(dec.pool._free) == dec.pool.n_pages
 
 
+def test_disagg_bf16_equals_monolithic():
+    """bf16 KV handoff: the schema carries the wire dtype by name (bf16's
+    ``dtype.str`` is ``"<V2"``, which numpy cannot cast from), and the
+    disaggregated tokens match the monolithic ones at the reduced config."""
+    import dataclasses
+    from repro.kvlayout import schema_from_config
+    from repro.launch import serve
+    cfg = dataclasses.replace(get_config("stablelm-3b").reduced(),
+                              param_dtype="bfloat16")
+    assert {c.dtype for c in schema_from_config(cfg).components} == \
+        {"bfloat16"}
+    params = serve.init_serving_params(cfg)
+    prompts, _ = serve.make_requests(cfg, 2, 40)
+    mono = serve.monolithic(cfg, params, prompts, 4)
+    done, _ = serve.disaggregated(cfg, params, prompts, 4)
+    assert [r["tokens"] for r in done] == mono
+
+
+def test_serving_steady_state_compiles_nothing():
+    """After one warm-up request, further requests of the same shape run
+    the jitted prefill/decode programs without a single compilation."""
+    from repro.launch import serve
+    from repro.launch.cache import CompileCounter
+    cfg = serve.serving_config("stablelm-3b", full=False)
+    params = serve.init_serving_params(cfg)
+    prompts, _ = serve.make_requests(cfg, 3, 32)
+    with CompileCounter() as warm:
+        serve.monolithic(cfg, params, prompts[:1], 4)
+        serve.disaggregated(cfg, params, prompts[:1], 4)
+    assert warm.by_name["jit(prefill)"] and warm.by_name["jit(decode_step)"]
+    with CompileCounter() as steady:
+        serve.monolithic(cfg, params, prompts[1:], 4)
+        serve.disaggregated(cfg, params, prompts[1:], 4)
+    assert steady.count == 0, dict(steady.by_name)
+
+
 def test_scheduler_drops_crashed_prefiller_from_view():
     """A crashed prefiller stops renewing its lease; the control plane
     declares it dead and the scheduler's routable view excludes it."""

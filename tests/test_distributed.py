@@ -34,7 +34,7 @@ def test_moe_a2a_matches_dense_oracle():
     from repro.models.moe import init_moe, moe_dense
     from repro.comm import moe_a2a, use_mesh
     cfg = get_config('qwen3-moe-30b-a3b').reduced()
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ('data', 'model'))
     p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     h = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model)) * 0.5
@@ -62,7 +62,7 @@ def test_sharded_train_step_matches_single_device():
     from repro.optim import init_adamw
     shape = dataclasses.replace(INPUT_SHAPES['train_4k'], seq_len=64, global_batch=4)
     cfg = get_config('gemma3-1b').reduced()
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ('data', 'model'))
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = init_adamw(params)
@@ -113,7 +113,7 @@ def test_explicit_reshard_beats_gspmd_fallback():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.comm.reshard import reshard_plan, fsdp_to_tp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ('data', 'model'))
     x = jnp.arange(1024*512, dtype=jnp.float32).reshape(1024, 512)
     xs = jax.device_put(x, NamedSharding(mesh, P(('data','model'), None)))

@@ -150,3 +150,29 @@ def test_flash_path_matches_chunked_in_model():
             A.FORCE_FLASH = False
         np.testing.assert_allclose(np.asarray(l_flash), np.asarray(l_ref),
                                    rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_flash_path_grads_match_chunked(window):
+    """The flash kernel is forward-only: attn_prefill's custom VJP
+    differentiates the chunked path, so gradients through the kernel
+    (FORCE_FLASH) equal those of the platform's default path."""
+    from repro.configs import get_config
+    from repro.models import attention as A
+    cfg = get_config("stablelm-3b").reduced()
+    p = A.init_attn(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
+
+    def grads():
+        loss = lambda p, x: (A.attn_prefill(p, x, cfg, window=window) ** 2).sum()
+        return jax.jit(jax.grad(loss, (0, 1)))(p, x)
+
+    g_ref = grads()
+    A.FORCE_FLASH = True
+    try:
+        g_flash = grads()
+    finally:
+        A.FORCE_FLASH = False
+    for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)

@@ -256,11 +256,12 @@ def test_pool_shared_allocator_across_components():
 # ---------------------------------------------------------------------------
 
 def _mono_generate(cfg, params, ids, n_decode, vision_emb=None):
-    # the launcher's reference loop — deliberately shared, and it uses a
+    # the launcher's reference loop — deliberately shared, but with a
     # DIFFERENT max_len than the handoff convention, proving the outputs
-    # are invariant to the cache headroom
+    # are invariant to the cache headroom (f32)
     from repro.launch.serve import monolithic
-    return monolithic(cfg, params, [ids], n_decode, vision_emb)[0]
+    return monolithic(cfg, params, [ids], n_decode, vision_emb,
+                      max_len=len(ids) + n_decode + 8)[0]
 
 
 @pytest.mark.slow
