@@ -754,10 +754,11 @@ class Fabric:
         # observability (repro.obs): None => every hook is a single guarded
         # attribute check; attach via Tracer(fabric) / attach_tracer,
         # HealthMonitor(fabric) / attach_health, FlightRecorder(fabric) /
-        # attach_recorder
+        # attach_recorder, and host-clock spans via attach_spans
         self.tracer = None
         self.health = None
         self.recorder = None
+        self.spans = None
         # fault injection (repro.core.faults): None => post_write's hot path
         # pays one attribute check and nothing else; attach via
         # FaultPlan(fabric, ...) which calls attach_faults
@@ -871,6 +872,14 @@ class Fabric:
         The recorder is fed by the health monitor's delivery stream and by
         ctrl-plane instants; it dumps its ring on failure paths only."""
         self.recorder = recorder
+
+    def attach_spans(self, rec) -> None:
+        """Attach host-clock spans (a :class:`repro.obs.HostSpans`, or any
+        object with its ``span``/``counters`` interface; None detaches).
+        The serving peers open spans per request and the event loop counts
+        the events it runs; virtual time is unchanged."""
+        self.spans = rec
+        self.loop.spans = rec
 
     def attach_faults(self, plan) -> None:
         """Attach a :class:`repro.core.faults.FaultPlan` (or None to

@@ -43,6 +43,9 @@ class EventLoop:
         self._cancelled: set = set()
         self.now: float = 0.0
         self._running = False
+        # host-clock spans (repro.obs.HostSpans) or None: while attached,
+        # each run adds the events it ran to the counter ``fabric.events``
+        self.spans = None
 
     def schedule(self, delay_us: float, fn: Callable[[], None]) -> None:
         """Run ``fn`` ``delay_us`` virtual microseconds from now (FIFO at ties)."""
@@ -86,6 +89,8 @@ class EventLoop:
             n += 1
             if n > max_events:
                 raise RuntimeError("event loop runaway (possible livelock)")
+        if self.spans is not None:
+            self.spans.counters["fabric.events"] += n
         return self.now
 
     def run_until(self, pred: Callable[[], bool], max_events: int = 10_000_000) -> float:
@@ -101,6 +106,8 @@ class EventLoop:
             n += 1
             if n > max_events:
                 raise RuntimeError("event loop runaway (possible livelock)")
+        if self.spans is not None:
+            self.spans.counters["fabric.events"] += n
         if not pred():
             raise RuntimeError("event queue drained before predicate held")
         return self.now

@@ -62,6 +62,10 @@ class TransferPlan:
                     n += 1
         self.n_slots = n          # pool pages per side, canonical order
         self.total_writes = n     # one WRITE per page
+        # bytes the handoff's WRITEs carry (one page length per slot)
+        self.write_bytes = sum(
+            self.comp_page_len[ci] * comp.n_stack * self.comp_chunks[ci]
+            for ci, comp in enumerate(schema.components))
 
     # -- introspection -------------------------------------------------------
     @property

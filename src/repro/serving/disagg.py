@@ -51,7 +51,7 @@ from ..ctrl import messages as m
 from ..kvlayout import (DECODE_MARGIN, KvSchema, TransferPlan, fill_cache,
                         schema_from_config, stage_cache)
 from ..models import decode_step_jit, init_cache, prefill_jit
-from ..obs import traced_phase
+from ..obs import host_count, host_span, traced_phase
 from .kvpool import KvPool
 
 
@@ -257,25 +257,37 @@ class Prefiller:
         start = max(t_start, self._busy_until)
         self._busy_until = start + cfg.n_layers * self.layer_compute_us
         delay0 = start - t_start
-        self.stats[f"req{req.request_id}_queued_us"] = delay0
         tr = self.fabric.tracer
         if tr is not None:
             tr.compute_span(f"{self.engine.node} gpu",
                             f"prefill:req{req.request_id}",
                             start, self._busy_until, phase="serving.prefill")
+        with host_span(self.fabric, "prefiller.request", rid=req.request_id,
+                       seq=S, queued_us=delay0):
+            self._serve(req, plan, delay0)
 
+    def _serve(self, req: DispatchReq, plan: TransferPlan,
+               delay0: float) -> None:
+        """Prefill an admitted request, stage its cache and arm the layer
+        spans' WRITEs, ``delay0`` virtual µs after now."""
+        cfg = self.cfg
         # REAL prefill compute (all layers at once — jax scan); both ends
         # derive cache geometry from plan.max_len so ring slot assignment
         # and padding agree bit-for-bit.
-        tokens = jnp.asarray(req.input_ids, jnp.int32)[None]
-        logits, cache = prefill_jit(
-            self.params, tokens, cfg, max_len=plan.max_len, moe_mode="dense",
-            vision_emb=_vision_batch(cfg, req.vision_emb))
+        with host_span(self.fabric, "prefiller.prefill",
+                       rid=req.request_id, seq=len(req.input_ids)):
+            tokens = jnp.asarray(req.input_ids, jnp.int32)[None]
+            logits, cache = prefill_jit(
+                self.params, tokens, cfg, max_len=plan.max_len,
+                moe_mode="dense",
+                vision_emb=_vision_batch(cfg, req.vision_emb))
         logits = logits[..., :cfg.vocab]   # drop vocab padding
 
         # stage EVERY schema component into pool slots, canonical order
         local_pages = self.pool.alloc(plan.n_slots)
-        stage_cache(plan, self.pool, local_pages, cache)
+        with host_span(self.fabric, "prefiller.stage", rid=req.request_id):
+            stage_cache(plan, self.pool, local_pages, cache)
+            host_count(self.fabric, "kv.staged_bytes", plan.write_bytes)
 
         # tail context: last-token logits
         tail = np.asarray(logits, np.float32).reshape(-1).view(np.uint8)
@@ -367,8 +379,6 @@ class Prefiller:
                 self.pool.free(local_pages)
                 self.inflight -= 1
                 self.inflight_slots -= plan.n_slots
-                self.stats[f"req{req.request_id}_prefill_us"] = \
-                    self.fabric.now - t_start
                 self._maybe_finish_drain()
             else:
                 self.fabric.loop.schedule(5.0, poll_free)
@@ -630,39 +640,47 @@ class Decoder:
     def _assemble_cache(self, request_id: int):
         r = self.results[request_id]
         plan: TransferPlan = r["plan"]
-        cache = init_cache(self.cfg, 1, plan.max_len)
-        for name, arr in fill_cache(plan, self.pool, r["pages"],
-                                    cache).items():
-            cache[name] = jnp.asarray(arr, cache[name].dtype)
+        with host_span(self.fabric, "decoder.fill", rid=request_id):
+            cache = init_cache(self.cfg, 1, plan.max_len)
+            for name, arr in fill_cache(plan, self.pool, r["pages"],
+                                        cache).items():
+                cache[name] = jnp.asarray(arr, cache[name].dtype)
+            host_count(self.fabric, "kv.filled_bytes", plan.write_bytes)
         return cache
 
     def _decode(self, request_id: int, n_decode: int) -> None:
         cfg = self.cfg
+        fab = self.fabric
         r = self.results[request_id]
-        tail_bytes = cfg.vocab * 4
-        logits = (self.tail_buf[r["tail_idx"] * tail_bytes:
-                                (r["tail_idx"] + 1) * tail_bytes]
-                  .view(np.float32).reshape(1, cfg.vocab))
-        cache = self._assemble_cache(request_id)
-        toks = [int(np.argmax(logits[0]))]
-        pos = r["seq_len"]
-        for _ in range(n_decode - 1):
-            lg, cache = decode_step_jit(
-                self.params, jnp.asarray([[toks[-1]]]),
-                jnp.asarray([pos], jnp.int32), cache, cfg, moe_mode="dense")
-            toks.append(int(jnp.argmax(lg[0])))
-            pos += 1
-        r["tokens"] = toks
-        self.pool.free(r["pages"])
-        self._tail_free.append(r["tail_idx"])
-        st = self._pending.pop(request_id, None)
-        if st is not None and st["reply_to"] is not None:
-            # stash the reply identity so a retransmitted SUBMIT for this
-            # attempt can replay the REQ-DONE (lost-ack recovery)
-            r["_reply_to"] = st["reply_to"]
-            r["_attempt"] = st["attempt"]
-            peer = self.client.peer_id if self.client else ""
-            self.engine.submit_send(st["reply_to"], m.encode(m.ReqDone(
-                request_id=request_id, attempt=st["attempt"], peer_id=peer,
-                ttft_us=r["ttft_us"], tokens=list(toks))))
-        self._maybe_finish_drain()
+        with host_span(fab, "decoder.request", rid=request_id,
+                       steps=n_decode - 1, ttft_us=r["ttft_us"]):
+            tail_bytes = cfg.vocab * 4
+            logits = (self.tail_buf[r["tail_idx"] * tail_bytes:
+                                    (r["tail_idx"] + 1) * tail_bytes]
+                      .view(np.float32).reshape(1, cfg.vocab))
+            cache = self._assemble_cache(request_id)
+            toks = [int(np.argmax(logits[0]))]
+            pos = r["seq_len"]
+            for _ in range(n_decode - 1):
+                with host_span(fab, "decoder.step", rid=request_id, pos=pos):
+                    lg, cache = decode_step_jit(
+                        self.params, jnp.asarray([[toks[-1]]]),
+                        jnp.asarray([pos], jnp.int32), cache, cfg,
+                        moe_mode="dense")
+                with host_span(fab, "decoder.sample", rid=request_id):
+                    toks.append(int(jnp.argmax(lg[0])))
+                pos += 1
+            r["tokens"] = toks
+            self.pool.free(r["pages"])
+            self._tail_free.append(r["tail_idx"])
+            st = self._pending.pop(request_id, None)
+            if st is not None and st["reply_to"] is not None:
+                # stash the reply identity so a retransmitted SUBMIT for
+                # this attempt can replay the REQ-DONE (lost-ack recovery)
+                r["_reply_to"] = st["reply_to"]
+                r["_attempt"] = st["attempt"]
+                peer = self.client.peer_id if self.client else ""
+                self.engine.submit_send(st["reply_to"], m.encode(m.ReqDone(
+                    request_id=request_id, attempt=st["attempt"],
+                    peer_id=peer, ttft_us=r["ttft_us"], tokens=list(toks))))
+            self._maybe_finish_drain()
