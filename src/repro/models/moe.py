@@ -2,8 +2,13 @@
 
 Three dispatch modes, mirroring the paper's baseline-vs-technique split:
 
-* ``dense``   — every expert computes every token, combined by router weight.
-                Exact; used as the oracle and for tiny smoke configs.
+* ``dense``   — exact: every token gets its top-k experts, nothing dropped.
+                ``moe_path`` picks the execution from the static token
+                count: with fewer selected (token, expert) pairs than
+                experts (decode), only the selected experts' weights are
+                read (``moe_routed_only``); otherwise every expert computes
+                every token, combined by router weight (``moe_all_experts``,
+                also the oracle).
 * ``scatter`` — capacity-based scatter/gather dispatch (GShard-style).  The
                 "collective-style" baseline: under pjit, GSPMD materialises
                 the token movement as all-gathers/dynamic-slices.
@@ -73,7 +78,13 @@ def _shared_out(p, h: jax.Array) -> jax.Array:
     return (jax.nn.silu(h @ p["swg"]) * (h @ p["swu"])) @ p["swd"]
 
 
-def moe_dense(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+def moe_path(n_tokens: int, cfg) -> str:
+    """``"routed"`` when the ``n_tokens * top_k`` selected expert slices are
+    fewer bytes than all ``n_routed`` experts, else ``"all"``."""
+    return "routed" if n_tokens * cfg.top_k < cfg.n_routed else "all"
+
+
+def moe_all_experts(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     """Exact all-experts path (oracle)."""
     T, D = h.shape
     logits = h.astype(jnp.float32) @ p["router"]
@@ -84,6 +95,44 @@ def moe_dense(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
         jnp.arange(T)[:, None], eids].set(gates.astype(h.dtype))
     y = jnp.einsum("te,etd->td", w, ye)
     return y + _shared_out(p, h), aux
+
+
+def moe_routed_only(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Exact path that reads only the ``T * top_k`` selected experts' weights.
+
+    Same router call on the same float32 logits as ``moe_all_experts``, so
+    the routing is identical.  Each (token, expert) pair slices its expert's
+    weights with a dynamic index, which XLA fuses into the dots; a gather
+    of the layer's (E, D, F) stack would first copy the whole layer when the
+    stack is itself a slice of a scanned layer axis.  SwiGLU and the gated
+    sum accumulate in float32.
+    """
+    f32 = jnp.float32
+    logits = h.astype(f32) @ p["router"]
+    gates, eids, aux = router_topk(logits, cfg.top_k)
+    T, k = eids.shape
+    rows = []
+    for t in range(T):
+        x = h[t:t + 1]
+        y = jnp.zeros((1, h.shape[1]), f32)
+        for j in range(k):
+            wg, wu, wd = (jax.lax.dynamic_index_in_dim(p[n], eids[t, j], keepdims=False)
+                          for n in ("wg", "wu", "wd"))
+            g = jnp.dot(x, wg, preferred_element_type=f32)
+            u = jnp.dot(x, wu, preferred_element_type=f32)
+            a = (jax.nn.silu(g) * u).astype(h.dtype)
+            y = y + gates[t, j] * jnp.dot(a, wd, preferred_element_type=f32)
+        rows.append(y)
+    y = jnp.concatenate(rows, 0).astype(h.dtype)
+    return y + _shared_out(p, h), aux
+
+
+def moe_dense(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Exact MoE: the routed-only or the all-experts path by ``moe_path``
+    (static under ``jit``: the token count is a shape)."""
+    if moe_path(h.shape[0], cfg) == "routed":
+        return moe_routed_only(p, h, cfg)
+    return moe_all_experts(p, h, cfg)
 
 
 def moe_scatter(p, h: jax.Array, cfg, capacity_factor: float = 1.25) -> Tuple[jax.Array, jax.Array]:

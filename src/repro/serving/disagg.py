@@ -51,6 +51,7 @@ from ..ctrl import messages as m
 from ..kvlayout import (DECODE_MARGIN, KvSchema, TransferPlan, fill_cache,
                         schema_from_config, stage_cache)
 from ..models import decode_step_jit, init_cache, prefill_jit
+from ..models.moe import moe_path
 from ..obs import host_count, host_span, traced_phase
 from .kvpool import KvPool
 
@@ -671,6 +672,8 @@ class Decoder:
                     toks.append(int(jnp.argmax(lg[0])))
                 pos += 1
             r["tokens"] = toks
+            host_count(fab, "decoder.routed_steps",
+                       n_decode - 1 if moe_path(1, cfg) == "routed" else 0)
             self.pool.free(r["pages"])
             self._tail_free.append(r["tail_idx"])
             st = self._pending.pop(request_id, None)

@@ -3,6 +3,7 @@
 The kernels are compiled at real widths for a ``v5e:2x2`` topology that is
 described, not attached: Mosaic refuses here what it would refuse on the
 chip (unaligned slices, VMEM overflow, unsupported dtypes), at no chip time.
+The routed MoE decode is checked the same way for what XLA makes of it.
 Nothing runs, so these tests say nothing about results or speed.
 
 The topology is described inside a fixture, never at import: only one
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import flash_attention as fa
 from repro.kernels import moe_combine, moe_pack, ssd_scan
+from repro.models.moe import init_moe, moe_forward, moe_path
 
 # DeepSeek-V3 dispatch geometry (benchmarks/bench_moe.py): hidden 7168,
 # top-8, 128 decode tokens per rank; a 7392-byte fp8 token + scales row.
@@ -78,3 +80,27 @@ def test_ssd_intra_compiles_at_mamba2_widths(one_chip):
     _compile(ssd_scan.ssd_intra_flat, one_chip,
              ((bc, h, cl, p), jnp.float32), ((bc, h, cl, 1), jnp.float32),
              ((bc, h, cl, n), jnp.float32), ((bc, h, cl, n), jnp.float32))
+
+
+def test_routed_moe_decode_copies_no_expert_stack(one_chip):
+    """One decode token through 4 scanned MoE layers at DeepSeekMoE-16B
+    widths: the selected experts' slices feed the dots directly.  A gather
+    of the scanned layer's (64, 2048, 1408) stack made XLA copy whole
+    stacks (369 MB each) into temporaries."""
+    cfg = get_config("deepseek-moe-16b")
+    assert moe_path(1, cfg) == "routed"
+    layer = jax.eval_shape(lambda: init_moe(jax.random.PRNGKey(0), cfg,
+                                            jnp.bfloat16))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (4,) + a.shape, a.dtype, sharding=one_chip), layer)
+    x = jax.ShapeDtypeStruct((1, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def decode(params, x):
+        def body(x, p_l):
+            return x + moe_forward(p_l, x, cfg, mode="dense")[0], None
+        return jax.lax.scan(body, x, params)[0]
+
+    compiled = jax.jit(decode).lower(params, x).compile()
+    stack = cfg.n_routed * cfg.d_model * cfg.d_ff_expert * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stack // 16
