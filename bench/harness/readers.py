@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from . import flops, trace
+from . import trace
 
 
 def per_request_ms(data, *names: str) -> Optional[float]:
@@ -33,9 +33,9 @@ def serving_mfu(data) -> Optional[float]:
     over the window times the chip's peak."""
     if data.red is None:
         return None
-    m, rec = data.sut.dims, data.sut.rec
-    work = (sum(flops.prefill_flops(m, a["seq"]) for *_, a in rec.named("model.prefill"))
-            + sum(flops.decode_flops(m, a["pos"]) for *_, a in rec.named("model.decode")))
+    a, m, rec = data.sut.arch, data.sut.dims, data.sut.rec
+    work = (sum(a.prefill_flops(m, s["seq"]) for *_, s in rec.named("model.prefill"))
+            + sum(a.decode_flops(m, s["pos"]) for *_, s in rec.named("model.decode")))
     if not work:
         return None
     return work / (data.red["window_s"] * data.chips

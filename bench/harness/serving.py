@@ -18,7 +18,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import model, traffic
+from . import arch, model, traffic
 from .spans import Recorder, patch
 
 # lease renewals a peer may make in one run: far beyond the virtual time a
@@ -29,8 +29,9 @@ MAX_RENEWALS = 10 ** 9
 class Serving:
     def __init__(self, conf: Dict, mix: Dict, seed: int):
         self.conf, self.mix, self.seed = conf, mix, seed
-        self.cfg = model.program_config(conf)
-        self.dims = model.dims(self.cfg)
+        self.arch = arch.get(conf)
+        self.cfg = self.arch.program_config(conf)
+        self.dims = self.arch.dims(self.cfg)
         self.rec = Recorder()
         self.requests: List[Dict] = []
 
@@ -38,7 +39,8 @@ class Serving:
     def setup(self) -> None:
         import jax
         self.params = jax.block_until_ready(
-            model.make_params(self.cfg, model.seed32(self.seed, "weights")))
+            model.make_params(self.arch, self.cfg,
+                              model.seed32(self.seed, "weights")))
         model.check_layout(self.cfg, self.params)
         self._build()
         # every prompt length once, with the longest output: each length
@@ -224,7 +226,7 @@ class Serving:
         if not sample:
             return []
         gaps = reference.served_gaps(
-            self.params, self.dims,
+            self.arch.forward, self.params, self.dims,
             [(r["prompt"], r["tokens"]) for r in sample],
             traffic.pad_to(self.mix), control=control)
         print(f"checked {sum(g.size for g in gaps)} served tokens of "

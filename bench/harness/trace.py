@@ -25,6 +25,7 @@ Definitions (each per device, then averaged over the devices used):
 
 from __future__ import annotations
 
+import heapq
 import re
 from collections import defaultdict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -110,14 +111,27 @@ def gaps(busy_iv: Sequence[Tuple[int, int]], lo: int, hi: int) -> List[Tuple[int
     return out
 
 
-def innermost(spans: Sequence[list], t: int, skip=(WINDOW,)) -> str:
-    best: Optional[list] = None
-    for e in spans:
-        if e[0] in skip or not (e[1] <= t < e[1] + e[2]):
-            continue
-        if best is None or e[2] < best[2]:
-            best = e
-    return best[0] if best else "host"
+def innermost(spans: Sequence[list], times: Sequence[int]) -> List[str]:
+    """For each of ``times``, the name of the innermost span open at it
+    (``start <= t < start + duration``): the shortest, and of equal ones
+    the first in ``spans``; ``host`` where none is open.  One sweep over
+    the spans and the times sorted by start, with a heap of the spans open
+    so far keyed by (duration, index): one that has ended leaves it when it
+    comes to the top."""
+    starts = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    heap: List[Tuple[int, int]] = []
+    out = ["host"] * len(times)
+    j = 0
+    for k in sorted(range(len(times)), key=times.__getitem__):
+        t = times[k]
+        while j < len(starts) and spans[starts[j]][1] <= t:
+            heapq.heappush(heap, (spans[starts[j]][2], starts[j]))
+            j += 1
+        while heap and spans[heap[0][1]][1] + heap[0][0] <= t:
+            heapq.heappop(heap)
+        if heap:
+            out[k] = spans[heap[0][1]][0]
+    return out
 
 
 def op_name(text: str) -> str:
@@ -178,8 +192,10 @@ def reduce(tr: Dict, n_devices: int, spans: Sequence[str] = (),
             if b > a:
                 modules[module_name(e[0])] += (b - a) * 1e-9 / n
                 counts[module_name(e[0])] += 1 / n
-        for a, b in gaps(iv, lo, hi):
-            idle[innermost(host, (a + b) // 2)] += (b - a) * 1e-9 / n
+        idle_iv = gaps(iv, lo, hi)
+        for (a, b), name in zip(idle_iv, innermost(
+                host, [(a + b) // 2 for a, b in idle_iv])):
+            idle[name] += (b - a) * 1e-9 / n
     rank = lambda d: [[k, v] for k, v in
                       sorted(d.items(), key=lambda kv: -kv[1])[:top]]
     return {"window_s": (hi - lo) * 1e-9, "busy_s": busy_s, "devices": n,

@@ -4,7 +4,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name in ``BENCHMARK.json``: the cell's configuration
-file, its traffic mix ``bench/traffic/<mix>.json``, its correctness limits
+file, the architecture module its ``model_type`` names
+(``bench/harness/arch/<model_type>.py``), its traffic mix
+``bench/traffic/<mix>.json``, its correctness limits
 ``bench/limits/<cell>.json`` and one reader per metric,
 ``bench/metrics/<metric>.py``.  ``--trace 0`` reports the cell's end-to-end
 metrics, ``--trace 1`` its per-layer metrics from spans and a device trace.
@@ -44,9 +46,11 @@ def load_cell(name: str) -> SimpleNamespace:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     conf_entry = {c["name"]: c for c in spec["configs"]}[cell["config"]]
-    from harness import model, traffic
+    from harness import arch, model, traffic
+    conf = model.load(ROOT / conf_entry["file"])
+    arch.get(conf)           # an unknown model_type fails here, before weights
     return SimpleNamespace(
-        spec=spec, cell=cell, conf=model.load(ROOT / conf_entry["file"]),
+        spec=spec, cell=cell, conf=conf,
         mix=traffic.load(BENCH / "traffic" / f"{cell['traffic']}.json"),
         limits=json.loads((BENCH / "limits" / f"{name}.json").read_text()))
 

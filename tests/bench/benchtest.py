@@ -18,19 +18,12 @@ def load_run():
     return mod
 
 
-# wide enough that logits spread as at full width, small enough for the CPU
-TINY_SERVING = dict(hidden_size=512, num_attention_heads=4,
-                    num_key_value_heads=4, intermediate_size=1024,
-                    vocab_size=4096)
-
-
-def shrink(c, layers=2):
-    """A cell cut to CPU size: same keys and code paths, tiny widths."""
-    conf, mix, lim = dict(c.conf), dict(c.mix), dict(c.limits)
-    conf.update(TINY_SERVING, num_hidden_layers=layers)
-    if "n_routed_experts" in conf:
-        # the published routing (64 experts, top-6, 2 shared), narrow
-        conf.update(moe_intermediate_size=64, num_hidden_layers=layers + 1)
+def shrink(c, layers=3):
+    """A cell cut to CPU size: its architecture's tiny cut with the
+    published routing (same keys and code paths, tiny widths)."""
+    from harness import arch
+    conf = arch.get(c.conf).tiny(c.conf, layers, "published")
+    mix, lim = dict(c.mix), dict(c.limits)
     mix.update(prompt_len={"16": 0.5, "32": 0.3, "64": 0.2},
                output_len={"uniform": [6, 10]})
     if mix["kind"] == "open_loop":

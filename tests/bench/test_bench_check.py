@@ -4,6 +4,7 @@ the program's place, reads as not correct."""
 
 import json
 import pathlib
+import time
 import types
 
 import jax
@@ -11,32 +12,30 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from harness import model, reference
-from benchtest import TINY_SERVING
+from harness import arch, model, reference, traffic
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = {c["name"]: ROOT / c["file"] for c in SPEC["configs"]}
 SERVING = [w["name"] for w in SPEC["workloads"]
-           if json.loads((ROOT / next(c["file"] for c in SPEC["configs"]
-                                      if c["name"] == w["config"])).read_text()
-                         )["system"] == "serving"]
+           if model.load(CONFIGS[w["config"]])["system"] == "serving"]
 
 
 def tiny_conf(name, layers=2):
-    conf = model.load(ROOT / "bench" / "configs" / f"{name}.json")
-    conf.update(TINY_SERVING, num_hidden_layers=layers, torch_dtype="float32")
-    if "n_routed_experts" in conf:
-        conf.update(moe_intermediate_size=64, n_routed_experts=8,
-                    num_experts_per_tok=2, n_shared_experts=1)
-    return conf
+    """Configuration ``name`` at CPU size with a small routing, in float32:
+    (its architecture module, the cut, the program's config)."""
+    conf = model.load(CONFIGS[name])
+    mod = arch.get(conf)
+    conf = mod.tiny(conf, layers, "small")
+    return mod, conf, mod.program_config(conf)
 
 
-@pytest.mark.parametrize("name", ["deepseek-moe-16b"])
+@pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_reference_matches_program_in_f32(name):
     from repro.models import decode_step_jit, prefill_jit
-    cfg = model.program_config(tiny_conf(name, 3))
-    m = model.dims(cfg)
-    p = model.make_params(cfg, 7)
+    mod, _, cfg = tiny_conf(name, 3)
+    m = mod.dims(cfg)
+    p = model.make_params(mod, cfg, 7)
     ids = np.random.default_rng(0).integers(0, cfg.vocab, 24).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         lg, cache = prefill_jit(p, jnp.asarray(ids)[None], cfg, max_len=40,
@@ -44,24 +43,25 @@ def test_reference_matches_program_in_f32(name):
         lg2, _ = decode_step_jit(p, jnp.asarray([[5]]),
                                  jnp.asarray([24], jnp.int32), cache, cfg,
                                  moe_mode="dense")
-    ref = np.asarray(reference.forward(p, np.append(ids, 5), m))
+    ref = np.asarray(mod.forward(p, np.append(ids, 5), m))
     scale = np.abs(ref).max()
     assert np.abs(np.asarray(lg)[0, :cfg.vocab] - ref[23]).max() < 1e-4 * scale
     assert np.abs(np.asarray(lg2)[0, :cfg.vocab] - ref[24]).max() < 1e-4 * scale
     # the control departs from the reference
-    ctl = np.asarray(reference.forward(p, np.append(ids, 5), m, quant=True))
+    ctl = np.asarray(mod.forward(p, np.append(ids, 5), m, quant=True))
     assert np.abs(ctl - ref).max() > 1e-3 * scale
 
 
 def test_served_gaps_read_the_reference():
-    cfg = model.program_config(tiny_conf("deepseek-moe-16b"))
-    m = model.dims(cfg)
-    p = model.make_params(cfg, 3)
+    mod, _, cfg = tiny_conf("deepseek-moe-16b")
+    m = mod.dims(cfg)
+    p = model.make_params(mod, cfg, 3)
     ids = np.arange(16, dtype=np.int32)
-    ref = np.asarray(reference.forward(p, ids, m))
+    ref = np.asarray(mod.forward(p, ids, m))
     best = [int(ref[15].argmax())]
     worst = [int(ref[15].argmin())]
-    gaps = reference.served_gaps(p, m, [(ids, best), (ids, worst)], 32)
+    gaps = reference.served_gaps(mod.forward, p, m,
+                                 [(ids, best), (ids, worst)], 32)
     assert len(gaps) == 2 and gaps[0].max() == 0.0
     assert gaps[1][0] == pytest.approx(ref[15].max() - ref[15].min(), rel=1e-5)
 
@@ -110,6 +110,22 @@ def _calibrate():
     return mod
 
 
+def _serve(sut, n):
+    """The seed's first ``n`` requests of the mix, sent at once and served
+    to completion: the same requests whatever the machine's speed, where
+    ``Serving.window`` serves for a time."""
+    gen = traffic.requests(sut.mix, sut.seed, sut.cfg.vocab)
+    sut.t0 = time.perf_counter()
+    for _ in range(n):
+        sut._submit(next(gen), sut.t0)
+    done = sut.sched.completed
+    sut.fab.run_until(lambda: all(r["rid"] in done for r in sut.requests))
+    sut.t_end = sut.t_close = time.perf_counter()
+    for r in sut.requests:
+        r["done"], r["tokens"] = sut.t_close, done[r["rid"]]["tokens"]
+    assert len(sut.completed()) == n
+
+
 @pytest.mark.parametrize("cell", SERVING)
 def test_control_reads_far_above_the_program(small_cell, cell):
     """The float8 control in the program's place, on the requests a run
@@ -119,7 +135,7 @@ def test_control_reads_far_above_the_program(small_cell, cell):
     for seed in (11, 2 ** 31 + 12, 13):
         sut = small_cell.system(c, seed)
         sut.setup()
-        sut.window(2.0)
+        _serve(sut, 8)
         sut.free()
         lower = cal.readings(sut, seed, c.limits)
         upper = cal.readings(sut, seed, c.limits, control=True)

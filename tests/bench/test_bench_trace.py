@@ -91,3 +91,68 @@ def test_recorded_cpu_trace(tmp_path):
     assert {"model.prefill", trace.WINDOW} <= names
     with pytest.raises(ValueError, match="no TPU device plane"):
         trace.reduce(tr, 1)
+
+
+def scan_innermost(spans, t):
+    """The per-gap scan the sweep replaced, kept as its oracle: every span
+    for each time, the shortest open one, the first of equals."""
+    best = None
+    for e in spans:
+        if not (e[1] <= t < e[1] + e[2]):
+            continue
+        if best is None or e[2] < best[2]:
+            best = e
+    return best[0] if best else "host"
+
+
+def _agree(spans, times):
+    got = trace.innermost(spans, times)
+    assert got == [scan_innermost(spans, t) for t in times]
+    return got
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_sweep_matches_the_scan_with_ties_and_nesting(seed):
+    """Random spans on a coarse clock, so starts, ends and durations tie
+    often; spans nest and overlap; times fall on every start and end."""
+    import numpy as np
+    r = np.random.default_rng(seed)
+    spans = []
+    for i in range(300):
+        t, d = int(r.integers(0, 400)), int(r.integers(0, 40))
+        spans.append([f"s{i % 7}", t, d])
+    for t, d in ((50, 10), (50, 10), (55, 10), (50, 200), (0, 1000)):
+        spans.append([f"tie{t}.{d}", t, d])       # exact ties, nested
+    times = sorted({e[1] for e in spans} | {e[1] + e[2] for e in spans}
+                   | {e[1] + e[2] - 1 for e in spans}
+                   | set(int(x) for x in r.integers(-5, 1100, 500)))
+    got = _agree(spans, list(r.permutation(times)))
+    assert "host" in got and len(set(got)) > 3
+    assert trace.innermost(spans, []) == [] and \
+        trace.innermost([], [1, 2]) == ["host", "host"]
+
+
+def test_sweep_matches_the_scan_on_a_recorded_trace(tmp_path):
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jax.numpy.ones((64, 64))
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation(trace.WINDOW):
+        for _ in range(20):
+            with jax.profiler.TraceAnnotation("fabric.loop"):
+                with jax.profiler.TraceAnnotation("model.decode"):
+                    f(x).block_until_ready()
+                with jax.profiler.TraceAnnotation("kv.stage"):
+                    f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    tr = trace.load(str(next(tmp_path.glob("**/*.xplane.pb"))))
+    lo, hi = trace.window(tr)
+    names = {"fabric.loop", "model.decode", "kv.stage"}
+    host = [e for e in trace.host_spans(tr) if e[0] in names]
+    assert len(host) == 60
+    times = list(range(lo, hi, max(1, (hi - lo) // 5000)))
+    times += [e[1] for e in host] + [e[1] + e[2] for e in host]
+    got = _agree(host, times)
+    assert names <= set(got)
