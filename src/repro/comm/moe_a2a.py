@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models.common import rms_norm
+from ..models.moe import router_select
 from .context import current_mesh, data_axes
 
 # capacity head-room over perfectly-balanced routing
@@ -90,13 +91,10 @@ def moe_a2a(p, h: jax.Array, cfg, ep_axis: str = "model",
     cap = max(1, int(T_lm * k / m * DISPATCH_FACTOR))
     Ce = max(1, (m * cap) // max(E_loc, 1))
 
-    def local(h_l, router, wg, wu, wd, *shared):
+    def local(h_l, rp, wg, wu, wd, *shared):
         # h_l: (T_lm, D) — sharded over data axes AND the EP axis.
         Tl = h_l.shape[0]
-        logits = h_l.astype(jnp.float32) @ router
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, eids = jax.lax.top_k(probs, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        gates, eids, probs = _select(h_l, rp, cfg)
         me = jax.lax.pmean(probs.mean(0), daxes + (ep_axis,))
         ce = jnp.zeros((E,), jnp.float32).at[eids.reshape(-1)].add(1.0)
         ce = jax.lax.pmean(ce / jnp.maximum(ce.sum(), 1.0), daxes + (ep_axis,))
@@ -165,11 +163,11 @@ def moe_a2a(p, h: jax.Array, cfg, ep_axis: str = "model",
         return y, aux
 
     in_specs = (P((*daxes, ep_axis), None),                  # h: fully sharded T
-                P(None, None),                               # router replicated
+                _router_specs(p),                            # router replicated
                 P(ep_axis, None, None),                      # experts EP-sharded
                 P(ep_axis, None, None),
                 P(ep_axis, None, None))
-    args = [h, p["router"], p["wg"], p["wu"], p["wd"]]
+    args = [h, _router_leaves(p), p["wg"], p["wu"], p["wd"]]
     if "swg" in p:
         in_specs = in_specs + (P(None, None),) * 3
         args += [p["swg"], p["swu"], p["swd"]]
@@ -178,6 +176,23 @@ def moe_a2a(p, h: jax.Array, cfg, ep_axis: str = "model",
     y, aux = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(*args)
     return y, aux
+
+
+def _router_leaves(p) -> tuple:
+    """The router's weights, with the selection bias of a sigmoid router."""
+    return (p["router"],) + ((p["router_bias"],) if "router_bias" in p else ())
+
+
+def _router_specs(p) -> tuple:
+    return tuple(P(*(None,) * x.ndim) for x in _router_leaves(p))
+
+
+def _select(h_l, rp, cfg):
+    """``router_select`` of local tokens by the router leaves ``rp``."""
+    router, *bias = rp
+    return router_select(h_l.astype(jnp.float32) @ router, cfg.top_k,
+                         cfg.router_score, bias[0] if bias else None,
+                         cfg.routed_scaling)
 
 
 def moe_ep_psum(p, h: jax.Array, cfg, ep_axis: str,
@@ -197,13 +212,10 @@ def moe_ep_psum(p, h: jax.Array, cfg, ep_axis: str,
     daxes = data_axes(mesh)
     cap = max(1, int(T // math_prod(mesh, daxes) * k / max(E_loc, 1) * DISPATCH_FACTOR))
 
-    def local(h_l, router, wg, wu, wd, *shared):
+    def local(h_l, rp, wg, wu, wd, *shared):
         Tl = h_l.shape[0]
         rank = jax.lax.axis_index(ep_axis)
-        logits = h_l.astype(jnp.float32) @ router
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, eids = jax.lax.top_k(probs, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        gates, eids, probs = _select(h_l, rp, cfg)
         me = jax.lax.pmean(probs.mean(0), daxes)
         ce = jnp.zeros((E,), jnp.float32).at[eids.reshape(-1)].add(1.0)
         ce = jax.lax.pmean(ce / jnp.maximum(ce.sum(), 1.0), daxes)
@@ -230,9 +242,9 @@ def moe_ep_psum(p, h: jax.Array, cfg, ep_axis: str,
         return y, aux
 
     in_specs = (P(daxes if daxes else None, None),
-                P(None, None),
+                _router_specs(p),
                 P(ep_axis, None, None), P(ep_axis, None, None), P(ep_axis, None, None))
-    args = [h, p["router"], p["wg"], p["wu"], p["wd"]]
+    args = [h, _router_leaves(p), p["wg"], p["wu"], p["wd"]]
     if "swg" in p:
         in_specs = in_specs + (P(None, None),) * 3
         args += [p["swg"], p["swu"], p["swd"]]

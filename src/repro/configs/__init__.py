@@ -6,14 +6,14 @@ from .base import ModelConfig
 from .shapes import INPUT_SHAPES, InputShape, shape_applicable, token_specs
 
 from . import (deepseek_moe_16b, gemma3_1b, granite_3_8b, granite_8b,
-               llama32_vision_90b, mamba2_780m, musicgen_large,
-               qwen3_moe_30b_a3b, stablelm_3b, zamba2_1_2b)
+               llama32_vision_90b, mamba2_780m, moonlight_16b_a3b,
+               musicgen_large, qwen3_moe_30b_a3b, stablelm_3b, zamba2_1_2b)
 
 _REGISTRY: Dict[str, ModelConfig] = {
     m.CONFIG.name: m.CONFIG
     for m in (deepseek_moe_16b, granite_3_8b, mamba2_780m, musicgen_large,
               qwen3_moe_30b_a3b, zamba2_1_2b, granite_8b, gemma3_1b,
-              llama32_vision_90b, stablelm_3b)
+              llama32_vision_90b, stablelm_3b, moonlight_16b_a3b)
 }
 
 ARCH_IDS: List[str] = sorted(_REGISTRY)

@@ -30,7 +30,13 @@ class ModelConfig:
     d_head: int = 0                 # 0 => d_model // n_heads
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
-    tie_embeddings: bool = True
+    tie_embeddings: bool = True     # False => a separate lm_head (D, V)
+
+    # -- multi-head latent attention (DeepSeek-V2/V3 MLA; 0 => off) -------------
+    kv_lora_rank: int = 0           # width of the cached, normalised latent
+    qk_nope_head_dim: int = 0       # per-head query/key width without rotary
+    qk_rope_head_dim: int = 0       # rotary width, the key's shared by all heads
+    v_head_dim: int = 0             # per-head value width
 
     # -- MoE ----------------------------------------------------------------
     n_routed: int = 0               # routed experts (0 => dense FFN)
@@ -39,6 +45,8 @@ class ModelConfig:
     d_ff_expert: int = 0
     first_k_dense: int = 0          # leading dense layers (DeepSeekMoE)
     router_aux_coef: float = 0.01   # load-balance loss coefficient
+    router_score: str = "softmax"   # softmax | sigmoid (noaux_tc: a per-expert bias selects)
+    routed_scaling: float = 1.0     # routed gates times this
 
     # -- SSM (Mamba2 / SSD) ----------------------------------------------------
     ssm_state: int = 0
@@ -69,6 +77,20 @@ class ModelConfig:
         if self.d_head:
             return self.d_head
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """MLA query/key width per head: the non-rotary and rotary parts."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """MLA cache row: the normalised latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def is_moe(self) -> bool:
@@ -155,15 +177,25 @@ class ModelConfig:
             cross_every=2 if self.cross_every else 0,
             vision_seq=min(self.vision_seq, 16) if self.vision_seq else 0,
             vision_dim=min(self.vision_dim, 64) if self.vision_dim else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
         )
         return dataclasses.replace(self, **changes)
 
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + layers)."""
+        """Parameters of the embedding, the untied head and the layers'
+        projections, routers and experts (norm weights left out)."""
         D, F, V = self.d_model, self.d_ff, self.vocab
         total = V * D * (1 if self.tie_embeddings else 2)
-        Hd = self.head_dim
-        attn = D * self.n_heads * Hd + 2 * D * self.n_kv_heads * Hd + self.n_heads * Hd * D
+        Hd, H = self.head_dim, self.n_heads
+        if self.is_mla:
+            attn = (D * H * self.qk_head_dim + D * self.latent_dim
+                    + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
+        else:
+            attn = D * H * Hd + 2 * D * self.n_kv_heads * Hd + H * Hd * D
         for kind, ffn in zip(self.layer_kinds(), self.ffn_kinds()):
             if kind in ("attn", "local", "global", "cross"):
                 total += attn
@@ -177,6 +209,7 @@ class ModelConfig:
                 total += self.n_routed * 3 * D * self.d_ff_expert
                 total += self.n_shared * 3 * D * self.d_ff_expert
                 total += D * self.n_routed
+                total += self.n_routed if self.router_score == "sigmoid" else 0
         if self.family == "hybrid":
             total += attn + 3 * D * self.d_ff  # one shared attention block
         if self.family == "vlm":

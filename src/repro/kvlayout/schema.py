@@ -5,9 +5,11 @@ per architecture: a uniform ``(L, S, K, Dh)`` k/v stack (stablelm, granite,
 qwen3-moe, musicgen), local+special ring/full stacks for pattern archs
 (gemma3 ``lk/lv/sk/sv``, llama-vision self/cross), per-layer SSM state
 blobs (mamba2 ``conv``/``ssd``), hybrid SSM + shared-attention rings
-(zamba2 ``ak/av``), or a head of dense layers in front of the scanned stack
-(deepseek ``k0/v0``).  The seed serving stack hard-coded the first shape
-and guarded the rest out via ``disagg_unsupported_reason``.
+(zamba2 ``ak/av``), a head of dense layers in front of the scanned stack
+(deepseek ``k0/v0``), or one latent row per token and layer in place of
+k/v (multi-head latent attention, ``latent0``/``latent``).  The seed
+serving stack hard-coded the first shape and guarded the rest out via
+``disagg_unsupported_reason``.
 
 A :class:`KvSchema` names each cache array as a *component* with:
 
@@ -256,13 +258,18 @@ def schema_from_config(cfg, page_tokens: int = 16) -> KvSchema:
                                          fixed_tokens=cfg.vision_seq))
         return KvSchema(cfg.name, cfg.n_layers, page_tokens, tuple(comps))
 
-    # attention families with a uniform scanned stack (+ optional dense head)
+    # attention families with a uniform scanned stack (+ optional dense head);
+    # latent attention moves one row of latent_dim values, no k/v
     k0 = cfg.first_k_dense
+    head_names, body_names = ("k0", "v0"), ("k", "v")
+    if cfg.is_mla:
+        tb = cfg.latent_dim * itemsize
+        head_names, body_names = ("latent0",), ("latent",)
     if k0:
         head = tuple(range(k0))
-        for name in ("k0", "v0"):
+        for name in head_names:
             comps.append(KvComponent(name, "token", head, dt, token_bytes=tb))
     body = tuple(range(k0, cfg.n_layers))
-    for name in ("k", "v"):
+    for name in body_names:
         comps.append(KvComponent(name, "token", body, dt, token_bytes=tb))
     return KvSchema(cfg.name, cfg.n_layers, page_tokens, tuple(comps))

@@ -1,4 +1,5 @@
-"""Attention: GQA with full / sliding-window / cross variants.
+"""Attention: GQA with full / sliding-window / cross variants, and
+multi-head latent attention (MLA, DeepSeek-V2/V3).
 
 Prefill uses a chunked (flash-style) implementation — a double scan over
 query and key/value blocks with a running (max, sum, acc) carry — so no
@@ -18,7 +19,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import apply_rope, dense_init, rms_norm, split_keys
+from .common import apply_rope, apply_rope_pairs, dense_init, rms_norm, split_keys
 
 NEG_INF = -1e30
 
@@ -31,6 +32,8 @@ FORCE_FLASH: bool = False
 
 
 def init_attn(key, cfg, dtype) -> Dict[str, jax.Array]:
+    if cfg.is_mla:
+        return init_mla(key, cfg, dtype)
     D, H, K, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ks = split_keys(key, 5)
     return {
@@ -53,16 +56,17 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       static_window: Optional[int] = None) -> jax.Array:
     """Memory-efficient attention.
 
-    q: (B, Sq, H, Dh);  k, v: (B, Sk, K, Dh) with H = K * G.
+    q: (B, Sq, H, Dh);  k: (B, Sk, K, Dh), v: (B, Sk, K, Dv) with H = K * G.
     ``window``: traced scalar; <=0 means full attention, otherwise sliding
     window of that many positions (query attends keys in (qpos-window, qpos]).
     ``static_window``: compile-time window — the kv scan is BANDED, visiting
     only the ceil((window+qb)/kb)+1 kv blocks that can intersect each query
     block (§Perf iteration C: local layers stop paying O(S^2)).
-    Returns (B, Sq, H, Dh).
+    Returns (B, Sq, H, Dv).
     """
     B, Sq, H, Dh = q.shape
     _, Sk, K, _ = k.shape
+    Dv = v.shape[-1]
     G = H // K
     scale = Dh ** -0.5
 
@@ -86,7 +90,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # k/v cotangents in backward)
     qr = (q * scale).reshape(B, nq, qb, K, G, Dh)
     kr = k.reshape(B, nk, kb, K, Dh)
-    vr = v.reshape(B, nk, kb, K, Dh)
+    vr = v.reshape(B, nk, kb, K, Dv)
     qpos = q_positions.reshape(nq, qb)
     kpos = k_positions.reshape(nk, kb)
 
@@ -132,14 +136,14 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
         m0 = jnp.full((B, K, G, qb), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, qb), jnp.float32)
-        a0 = jnp.zeros((B, K, G, qb, Dh), jnp.float32)
+        a0 = jnp.zeros((B, K, G, qb, Dv), jnp.float32)
         ks = jnp.arange(n_rel if n_rel is not None else nk)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), ks)
-        out = acc / jnp.maximum(l, 1e-20)[..., None]                # (B,K,G,qb,Dh)
-        return out.transpose(0, 3, 1, 2, 4)                          # (B,qb,K,G,Dh)
+        out = acc / jnp.maximum(l, 1e-20)[..., None]                # (B,K,G,qb,Dv)
+        return out.transpose(0, 3, 1, 2, 4)                          # (B,qb,K,G,Dv)
 
-    out = jax.lax.map(q_step, jnp.arange(nq))                        # (nq,B,qb,K,G,Dh)
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * qb, H, Dh)
+    out = jax.lax.map(q_step, jnp.arange(nq))                        # (nq,B,qb,K,G,Dv)
+    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * qb, H, Dv)
     return out[:, :Sq].astype(q.dtype)
 
 
@@ -284,3 +288,95 @@ def attn_decode(p: Dict[str, jax.Array], x: jax.Array, cfg, *,
     o = o / pattn.sum(-1)[..., None]
     out = o.reshape(B, 1, H * Dh).astype(x.dtype) @ p["wo"]
     return out, k_cache, v_cache
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention (MLA)
+#
+# Per the published DeepSeek-V3 modeling code (no query compression):
+# q = x·W_q, split per head into a non-rotary and a rotary part;
+# [c, k_pe] = x·W_kv_a; c goes through its own RMS norm; [k_nope, v] =
+# c·W_kv_b per head.  Rotary turns q's rotary part and the one k_pe that all
+# heads share, pairing dims (2i, 2i+1).  The cache holds [c, roped k_pe],
+# ``latent_dim`` values a token a layer.  Softmax scale: qk_head_dim^-0.5.
+# --------------------------------------------------------------------------
+
+# the published code builds the latent's norm with its default epsilon
+MLA_KV_NORM_EPS = 1e-6
+
+
+def init_mla(key, cfg, dtype) -> Dict[str, jax.Array]:
+    D, H, C = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    Hv = H * cfg.v_head_dim
+    ks = split_keys(key, 4)
+    return {
+        "norm": jnp.zeros((D,), dtype),
+        "wq": dense_init(ks[0], (D, H * cfg.qk_head_dim), dtype),
+        "wkv_a": dense_init(ks[1], (D, cfg.latent_dim), dtype),
+        "kv_norm": jnp.zeros((C,), dtype),
+        "wkv_b": dense_init(ks[2], (C, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dtype),
+        "wo": dense_init(ks[3], (Hv, D), dtype, scale=Hv ** -0.5),
+    }
+
+
+def _mla_qc(p, x, cfg, positions):
+    """Queries (..., H, qk_head_dim) and cache rows (..., latent_dim) of
+    ``x`` (B, S, D) at ``positions`` (S,) or (B, 1)."""
+    H, N, C = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    h = rms_norm(x, p["norm"], cfg.norm_eps)
+    q = _split_heads(h @ p["wq"], H, cfg.qk_head_dim)
+    q = jnp.concatenate(
+        [q[..., :N], apply_rope_pairs(q[..., N:], positions, cfg.rope_theta)], -1)
+    kv_a = h @ p["wkv_a"]
+    c = rms_norm(kv_a[..., :C], p["kv_norm"], MLA_KV_NORM_EPS)
+    k_pe = apply_rope_pairs(kv_a[..., None, C:], positions, cfg.rope_theta)
+    return q, jnp.concatenate([c, k_pe[..., 0, :]], -1)
+
+
+def mla_prefill(p: Dict[str, jax.Array], x: jax.Array, cfg,
+                positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Causal MLA over a full sequence, the latent decompressed into per-head
+    keys and values, attended by ``chunked_attention`` on every platform
+    (the flash kernel takes one width for queries, keys and values).
+    x: (B, S, D).  Returns (out (B,S,D), cache rows (B, S, latent_dim))."""
+    with jax.named_scope("mla_prefill"):
+        B, S, _ = x.shape
+        H, N, R = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        C, V = cfg.kv_lora_rank, cfg.v_head_dim
+        q, lat = _mla_qc(p, x, cfg, positions)
+        kv = _split_heads(lat[..., :C] @ p["wkv_b"], H, N + V)
+        k = jnp.concatenate(
+            [kv[..., :N], jnp.broadcast_to(lat[:, :, None, C:], (B, S, H, R))], -1)
+        o = chunked_attention(q, k, kv[..., N:], positions, positions, causal=True)
+        return o.reshape(B, S, H * V) @ p["wo"], lat
+
+
+def mla_decode(p: Dict[str, jax.Array], x: jax.Array, cfg, *,
+               cache: jax.Array, positions: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """One-token MLA against the latent cache, absorbed: ``W_uk`` folds into
+    the query and ``W_uv`` into the output, so no per-head key or value is
+    rebuilt.  x: (B, 1, D); cache: (B, Smax, latent_dim); positions: (B,),
+    the new token's index, whose row is written first.  Returns (out
+    (B,1,D), cache)."""
+    with jax.named_scope("mla_decode"):
+        B = x.shape[0]
+        H, N, C, V = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
+        f32 = jnp.float32
+        q, row = _mla_qc(p, x, cfg, positions[:, None])
+        cache = cache.at[jnp.arange(B), positions].set(row[:, 0].astype(cache.dtype))
+        wkv_b = p["wkv_b"].reshape(C, H, N + V)
+        q_lat = jnp.einsum("bhn,chn->bhc", q[:, 0, :, :N], wkv_b[..., :N],
+                           preferred_element_type=f32)
+        qf = jnp.concatenate([q_lat, q[:, 0, :, N:].astype(f32)], -1)
+        qf = (qf * cfg.qk_head_dim ** -0.5).astype(cache.dtype)
+        s = jnp.einsum("bhc,btc->bht", qf, cache, preferred_element_type=f32)
+        live = jnp.arange(cache.shape[1])[None, :] <= positions[:, None]
+        s = jnp.where(live[:, None], s, NEG_INF)
+        pr = jnp.exp(s - s.max(-1, keepdims=True))
+        o_lat = jnp.einsum("bht,btc->bhc", pr.astype(cache.dtype), cache[..., :C],
+                           preferred_element_type=f32) / pr.sum(-1)[..., None]
+        o = jnp.einsum("bhc,chv->bhv", o_lat.astype(x.dtype), wkv_b[..., N:],
+                       preferred_element_type=f32)
+        out = o.reshape(B, 1, H * V).astype(x.dtype) @ p["wo"]
+        return out, cache

@@ -32,6 +32,19 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def apply_rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over adjacent dims (2i, 2i+1), the pairing of the
+    published DeepSeek-V3 weights.  x: (..., seq, heads, dim)."""
+    d = x.shape[-1]
+    angles = positions[..., None].astype(jnp.float32) * rope_freqs(d, theta)
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def dense_init(key: jax.Array, shape: Tuple[int, ...], dtype, scale: Optional[float] = None) -> jax.Array:
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     std = scale if scale is not None else fan_in ** -0.5

@@ -10,6 +10,8 @@ Public API (pure functions over param pytrees):
 
 Caches (per family):
   attn:   {"k","v": (L,B,Smax,K,Dh)}  [+ {"ck","cv": (L,B,Sv,K,Dh)} for vlm]
+  mla:    {"latent": (L,B,Smax,kv_lora_rank+qk_rope_head_dim)}
+          [+ "latent0" for the first_k_dense layers, in place of "k0","v0"]
   ssm:    {"conv": (L,B,K-1,convdim), "ssd": (L,B,H,P,N)}
   hybrid: ssm caches (L=n_mamba) + ring KV for the shared attention block:
           {"ak","av": (n_groups? no — single shared block per application is
@@ -24,7 +26,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import attn_decode, attn_prefill, init_attn
+from .attention import (attn_decode, attn_prefill, init_attn, mla_decode,
+                        mla_prefill)
 from .blocks import layer_metadata, stacked_init
 from .common import dense_init, rms_norm, split_keys
 from .mlp import init_mlp, mlp_forward
@@ -62,6 +65,8 @@ def init_params(cfg, key: jax.Array) -> Params:
         "embed": dense_init(ks[0], (padded_vocab(cfg), D), dtype, scale=0.02),
         "final_norm": jnp.zeros((D,), dtype),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense_init(ks[6], (D, padded_vocab(cfg)), dtype)
     if cfg.family == "vlm":
         params["vision_proj"] = dense_init(ks[1], (cfg.vision_dim, D), dtype)
 
@@ -106,7 +111,8 @@ def _embed(params, tokens, cfg):
 
 def _unembed(params, x, cfg):
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (h @ params["embed"].T).astype(jnp.float32)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = (h @ head).astype(jnp.float32)
     Vp = logits.shape[-1]
     if Vp != cfg.vocab:   # mask vocab-padding rows
         pad_mask = jnp.arange(Vp) < cfg.vocab
@@ -264,7 +270,10 @@ def forward_train(params: Params, tokens: jax.Array, cfg, *,
     positions = jnp.arange(S, dtype=jnp.int32)
 
     for d0 in params.get("dense0", []):
-        x = x + attn_prefill(d0["attn"], x, cfg, positions=positions)
+        if cfg.is_mla:
+            x = x + mla_prefill(d0["attn"], x, cfg, positions)[0]
+        else:
+            x = x + attn_prefill(d0["attn"], x, cfg, positions=positions)
         x = x + mlp_forward(d0["mlp"], x, cfg.norm_eps)
 
     k0 = cfg.first_k_dense
@@ -274,6 +283,8 @@ def forward_train(params: Params, tokens: jax.Array, cfg, *,
         p_l, window_l, is_cross_l = xs
 
         def self_branch(x):
+            if cfg.is_mla:
+                return mla_prefill(p_l["attn"], x, cfg, positions)[0]
             return attn_prefill(p_l["attn"], x, cfg, window=window_l,
                                 positions=positions)
 
@@ -347,6 +358,12 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None) -> Dict[str, jax.Array
         cache["lv"] = jnp.zeros((n_loc, batch, W, K, Dh), dtype)
         cache["sk"] = jnp.zeros((n_spe, batch, S_spec, K, Dh), dtype)
         cache["sv"] = jnp.zeros((n_spe, batch, S_spec, K, Dh), dtype)
+    elif cfg.is_mla:
+        L = cfg.n_layers - cfg.first_k_dense
+        cache["latent"] = jnp.zeros((L, batch, max_len, cfg.latent_dim), dtype)
+        if cfg.first_k_dense:
+            cache["latent0"] = jnp.zeros(
+                (cfg.first_k_dense, batch, max_len, cfg.latent_dim), dtype)
     else:
         L = cfg.n_layers - cfg.first_k_dense
         cache["k"] = jnp.zeros((L, batch, max_len, K, Dh), dtype)
@@ -573,6 +590,8 @@ def prefill(params: Params, tokens: jax.Array, cfg, *,
         return _unembed(params, x[:, -1:], cfg)[:, 0], cache
 
     # ---- attention families -------------------------------------------------
+    if cfg.is_mla:
+        return _prefill_mla(params, x, cfg, cache, max_len, moe_mode)
     meta = layer_metadata(cfg)
     positions = jnp.arange(S, dtype=jnp.int32)
     k0 = cfg.first_k_dense
@@ -702,6 +721,8 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
         return _unembed(params, x, cfg)[:, 0], new_cache
 
     # ---- attention families --------------------------------------------------
+    if cfg.is_mla:
+        return _decode_mla(params, x, positions, new_cache, cfg, moe_mode)
     meta = layer_metadata(cfg)
     k0 = cfg.first_k_dense
     for i, d0 in enumerate(params.get("dense0", [])):
@@ -749,6 +770,57 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     x, (k, v) = jax.lax.scan(body, x, xs)
     new_cache["k"], new_cache["v"] = k, v
     return _unembed(params, x, cfg)[:, 0], new_cache
+
+
+# --------------------------------------------------------------------------
+# latent attention (MLA): one latent cache row per token and layer
+# --------------------------------------------------------------------------
+
+def _ffn(p, x, cfg, moe_mode):
+    if cfg.is_moe:
+        return moe_forward(p, x, cfg, mode=moe_mode)[0]
+    return mlp_forward(p, x, cfg.norm_eps)
+
+
+def _prefill_mla(params, x, cfg, cache, max_len, moe_mode):
+    S = x.shape[1]
+    positions = jnp.arange(S, dtype=jnp.int32)
+
+    def pad(lat):  # (B,S,latent) -> (B,max_len,latent)
+        return jnp.pad(lat, ((0, 0), (0, max_len - S), (0, 0)))
+
+    for i, d0 in enumerate(params.get("dense0", [])):
+        out, lat = mla_prefill(d0["attn"], x, cfg, positions)
+        x = x + out
+        x = x + mlp_forward(d0["mlp"], x, cfg.norm_eps)
+        cache["latent0"] = cache["latent0"].at[i].set(pad(lat))
+
+    def body(x, p_l):
+        out, lat = mla_prefill(p_l["attn"], x, cfg, positions)
+        x = x + out
+        return x + _ffn(p_l["ffn"], x, cfg, moe_mode), pad(lat)
+
+    x, cache["latent"] = jax.lax.scan(body, x, params["layers"])
+    return _unembed(params, x[:, -1:], cfg)[:, 0], cache
+
+
+def _decode_mla(params, x, positions, cache, cfg, moe_mode):
+    for i, d0 in enumerate(params.get("dense0", [])):
+        out, lat = mla_decode(d0["attn"], x, cfg, cache=cache["latent0"][i],
+                              positions=positions)
+        x = x + out
+        x = x + mlp_forward(d0["mlp"], x, cfg.norm_eps)
+        cache["latent0"] = cache["latent0"].at[i].set(lat)
+
+    def body(x, xs):
+        p_l, lat_l = xs
+        out, lat = mla_decode(p_l["attn"], x, cfg, cache=lat_l,
+                              positions=positions)
+        x = x + out
+        return x + _ffn(p_l["ffn"], x, cfg, moe_mode), lat
+
+    x, cache["latent"] = jax.lax.scan(body, x, (params["layers"], cache["latent"]))
+    return _unembed(params, x, cfg)[:, 0], cache
 
 
 # Serving entry points: one compiled program per (config, shapes), with the

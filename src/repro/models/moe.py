@@ -45,17 +45,43 @@ def init_moe(key, cfg, dtype) -> Dict[str, jax.Array]:
             "swu": dense_init(ks[5], (D, Fs), dtype),
             "swd": dense_init(ks[6], (Fs, D), dtype, scale=Fs ** -0.5),
         })
+    if cfg.router_score == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
     return p
 
 
-def router_topk(logits: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Softmax router with renormalised top-k gates + aux load-balance loss.
+def router_select(logits: jax.Array, top_k: int, score: str = "softmax",
+                  bias: Optional[jax.Array] = None, scale: float = 1.0
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The routing decision: renormalised top-k gates.
 
-    logits: (T, E) float32.  Returns (gates (T,k), eids (T,k), aux_loss).
+    ``score``: ``softmax`` over the experts, or ``sigmoid`` per expert
+    (DeepSeek-V3's ``noaux_tc`` with one group): there ``bias`` (E,) is
+    added to the scores to choose the top-k, and the gates are the unbiased
+    scores of the chosen experts.  Gates are renormalised, then times
+    ``scale``.  logits: (T, E) float32.  Returns (gates (T,k), eids (T,k),
+    probs (T,E)): ``probs`` are the scores the aux loss balances.
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, eids = jax.lax.top_k(probs, top_k)
+    if score == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, eids = jax.lax.top_k(probs + bias, top_k)
+        gates = jnp.take_along_axis(probs, eids, -1)
+        probs = probs / probs.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        gates, eids = jax.lax.top_k(probs, top_k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gates = gates * scale
+    return gates, eids, probs
+
+
+def router_topk(logits: jax.Array, top_k: int, score: str = "softmax",
+                bias: Optional[jax.Array] = None, scale: float = 1.0
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``router_select`` + aux load-balance loss.  Returns (gates (T,k),
+    eids (T,k), aux_loss)."""
+    gates, eids, probs = router_select(logits, top_k, score, bias, scale)
     # Switch-style aux loss: E * sum_e f_e * P_e
     E = logits.shape[-1]
     me = probs.mean(0)                                          # (E,)
@@ -63,6 +89,13 @@ def router_topk(logits: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, ja
     ce = ce / jnp.maximum(ce.sum(), 1.0)
     aux = E * jnp.sum(me * ce)
     return gates, eids, aux
+
+
+def route(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``router_topk`` of tokens ``h`` (T, D) by the layer's router."""
+    logits = h.astype(jnp.float32) @ p["router"]
+    return router_topk(logits, cfg.top_k, cfg.router_score,
+                       p.get("router_bias"), cfg.routed_scaling)
 
 
 def _experts_swiglu(p, xe: jax.Array) -> jax.Array:
@@ -87,8 +120,7 @@ def moe_path(n_tokens: int, cfg) -> str:
 def moe_all_experts(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     """Exact all-experts path (oracle)."""
     T, D = h.shape
-    logits = h.astype(jnp.float32) @ p["router"]
-    gates, eids, aux = router_topk(logits, cfg.top_k)
+    gates, eids, aux = route(p, h, cfg)
     # (E, T, D) expert outputs
     ye = _experts_swiglu(p, jnp.broadcast_to(h[None], (cfg.n_routed, T, D)))
     w = jnp.zeros((T, cfg.n_routed), h.dtype).at[
@@ -108,8 +140,7 @@ def moe_routed_only(p, h: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     sum accumulate in float32.
     """
     f32 = jnp.float32
-    logits = h.astype(f32) @ p["router"]
-    gates, eids, aux = router_topk(logits, cfg.top_k)
+    gates, eids, aux = route(p, h, cfg)
     T, k = eids.shape
     rows = []
     for t in range(T):
@@ -144,8 +175,7 @@ def moe_scatter(p, h: jax.Array, cfg, capacity_factor: float = 1.25) -> Tuple[ja
     T, D = h.shape
     E, k = cfg.n_routed, cfg.top_k
     C = max(1, int(T * k / E * capacity_factor))
-    logits = h.astype(jnp.float32) @ p["router"]
-    gates, eids, aux = router_topk(logits, k)
+    gates, eids, aux = route(p, h, cfg)
 
     fe = eids.reshape(-1)                                   # (T*k,)
     fg = gates.reshape(-1).astype(h.dtype)

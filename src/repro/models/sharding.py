@@ -50,6 +50,16 @@ def param_spec_tree(cfg, mesh: Mesh):
 
     def attn_spec(stacked: bool):
         lead = (None,) if stacked else ()
+        if cfg.is_mla:
+            sq = "model" if shard_q else None
+            return {
+                "norm": P(*lead, None),
+                "wq": P(*lead, None, sq),
+                "wkv_a": P(*lead, None, None),     # the latent is shared by heads
+                "kv_norm": P(*lead, None),
+                "wkv_b": P(*lead, None, sq),
+                "wo": P(*lead, sq, None),
+            }
         return {
             "norm": P(*lead, None),
             "wq": P(*lead, None, "model") if shard_q else P(*lead, None, None),
@@ -82,6 +92,8 @@ def param_spec_tree(cfg, mesh: Mesh):
             ss = "model" if shard_shared_ff else None
             spec.update({"swg": P(*lead, None, ss), "swu": P(*lead, None, ss),
                          "swd": P(*lead, ss, None)})
+        if cfg.router_score == "sigmoid":
+            spec["router_bias"] = P(*lead, None)
         return spec
 
     def mamba_spec(stacked: bool):
@@ -107,6 +119,8 @@ def param_spec_tree(cfg, mesh: Mesh):
         "embed": P("model", None) if shard_vocab else P(None, None),  # padded vocab
         "final_norm": P(None),
     }
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(None, "model") if shard_vocab else P(None, None)
     if cfg.family == "vlm":
         specs["vision_proj"] = P(None, "model") if shard_dmodel else P(None, None)
 
@@ -179,6 +193,10 @@ def cache_spec_tree(cfg, mesh: Mesh, batch: int, seq_len: int) -> Dict[str, P]:
         specs["lv"] = P(None, bspec, wseq, kv_head, None)
         specs["sk"] = P(None, bspec, sseq, kv_head, None)
         specs["sv"] = P(None, bspec, sseq, kv_head, None)
+    elif cfg.is_mla:
+        specs["latent"] = P(None, bspec, seq_spec, None)
+        if cfg.first_k_dense:
+            specs["latent0"] = P(None, bspec, seq_spec, None)
     else:
         specs["k"] = P(None, bspec, seq_spec, kv_head, None)
         specs["v"] = P(None, bspec, seq_spec, kv_head, None)

@@ -674,6 +674,10 @@ class Decoder:
             r["tokens"] = toks
             host_count(fab, "decoder.routed_steps",
                        n_decode - 1 if moe_path(1, cfg) == "routed" else 0)
+            if cfg.is_mla:
+                # latent rows the steps read: pos + 1 a layer, step by step
+                host_count(fab, "decoder.cache_rows", cfg.n_layers * sum(
+                    range(r["seq_len"] + 1, r["seq_len"] + n_decode)))
             self.pool.free(r["pages"])
             self._tail_free.append(r["tail_idx"])
             st = self._pending.pop(request_id, None)
